@@ -147,6 +147,9 @@ def _spawn(args, host: int, n_hosts: int, exchange: str,
         cmd += ["--exchange", exchange]
     cmd += list(extra)          # argparse keeps the LAST occurrence: extra
     env = dict(os.environ)      # may override --rounds etc. per case
+    # workers measure host memory and the exchange; several run at once,
+    # and a chip belongs to one process, so they never touch it
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     if args.host_devices:
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="

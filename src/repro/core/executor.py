@@ -517,21 +517,17 @@ class VmapExecutor:
     def _round_fn(self, ctx: RoundContext) -> Callable:
         fn = ctx.jit_cache.get("round")
         if fn is None:
-            # the (xs, ys, ex_mask) batch stacks are rebuilt fresh every
-            # round, so their buffers can be donated back to XLA — a real
-            # win on accelerators, a warning no-op on the CPU backend
-            donate = (() if jax.default_backend() == "cpu" else (3, 4, 5))
+            # no buffer donation: the (xs, ys, ex_mask) batch stacks match
+            # no output's shape, so the TPU compiler reports them unusable
             if ctx.batched_local_update is not None:
                 # client-batched body: one fused cohort program (stacked
                 # params through the model, grouped-conv kernels) instead
                 # of vmapping the per-client scan — same signature
-                fn = jax.jit(ctx.batched_local_update,
-                             donate_argnums=donate)
+                fn = jax.jit(ctx.batched_local_update)
             else:
                 fn = jax.jit(jax.vmap(ctx.local_update,
                                       in_axes=(None, None, 0, 0, 0, 0, 0, 0,
-                                               None)),
-                             donate_argnums=donate)
+                                               None)))
             ctx.jit_cache["round"] = fn
         return fn
 

@@ -7,15 +7,17 @@ SAME processes up as one ``jax.distributed`` topology, so collectives,
 ``jax.process_count()``-aware mesh selection
 (``executor.ShardMapExecutor`` shards each host's cohort slice over
 ``jax.local_devices()``) and the process-local global-array stitch
-(``sharding.make_array_from_process_local_data_compat``'s non-fallback
-branch) all run for real.
+(``sharding.make_array_from_process_local_data_compat`` across
+processes) all run for real.
 
-Typical 2-host CPU launch (each process forcing 2 host devices):
+Typical 2-host CPU launch (each process forcing 2 host devices; the CLI
+smoke pins ``JAX_PLATFORMS=cpu`` itself, so its ranks never contend for
+an accelerator):
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
     python -m repro.launch.distributed \\
         --coordinator 127.0.0.1:<port> --num-processes 2 --process-id 0 &
-    XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
     python -m repro.launch.distributed \\
         --coordinator 127.0.0.1:<port> --num-processes 2 --process-id 1
 
@@ -118,7 +120,10 @@ def _smoke(args) -> int:
 
 def main(argv=None) -> int:
     import argparse
+    import os
 
+    # the smoke is a CPU topology: set before jax first touches a backend
+    os.environ["JAX_PLATFORMS"] = "cpu"
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--coordinator", required=True,
                     help="coordinator address, host:port (rank 0 binds it)")

@@ -30,9 +30,10 @@ from repro.core.distillation import ensemble_average
 from repro.core.server import ModelBuffer, weighted_average
 from repro.data.synthetic import lm_token_batches
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 from repro.optim import sgd
-from repro.sharding import shard_map_compat
+from repro.sharding import make_mesh_compat, shard_map_compat
 
 Params = Any
 
@@ -197,7 +198,7 @@ def run_sharded(cfg, *, rounds: int, batches_per_round: int, batch: int,
     round_clock = make_round_clock(n_clients, straggler_frac=straggler_frac,
                                    straggler_slowdown=straggler_slowdown,
                                    seed=seed)
-    mesh = jax.make_mesh((n_clients,), ("clients",))
+    mesh = make_mesh_compat((n_clients,), ("clients",))
     kd_mode = "teacher" if algo == "fedgkd" else "none"
     round_fn = make_parallel_round(cfg, mesh, gamma=gamma, lr=lr,
                                    kd_mode=kd_mode)
@@ -302,6 +303,7 @@ def main(argv=None) -> int:
                          "barrier cost on the virtual clock)")
     ap.add_argument("--straggler-slowdown", type=float, default=4.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.fl_task:
         return run_fl_task(args)

@@ -29,7 +29,7 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
 
@@ -40,74 +40,40 @@ def data_axes(mesh: Mesh) -> tuple:
 
 
 def make_mesh_compat(shape: tuple[int, ...], axis_names: tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
-    ``jax.make_mesh`` (with its device-order heuristics) appeared in
-    jax 0.4.35; on older releases fall back to
-    ``mesh_utils.create_device_mesh`` + the ``Mesh`` constructor, which is
-    what ``make_mesh`` wraps.  Every mesh in this repo (production pods,
-    host test meshes, the executor's ``("clients",)`` mesh) goes through
-    here so a jax bump only has one seam to patch.
+    jax's own default is ``Explicit`` axes, under which plain indexing of a
+    sharded array (``l[:k]`` on the executor's client-sharded outputs)
+    raises ``ShardingTypeError``.  This repo shards with ``shard_map`` and
+    ``NamedSharding`` placement, which is what ``Auto`` axes mean.  Every
+    mesh in this repo (production pods, host test meshes, the executor's
+    ``("clients",)`` mesh) goes through here.  A mesh smaller than the
+    visible device set takes the first ``prod(shape)`` devices.
     """
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        return mk(shape, axis_names)
-    from jax.experimental import mesh_utils
-    # match make_mesh: a mesh smaller than the visible device set takes the
-    # first prod(shape) devices (create_device_mesh would raise instead)
-    n = int(np.prod(shape))
-    devs = mesh_utils.create_device_mesh(shape, devices=jax.devices()[:n])
-    return Mesh(devs, axis_names)
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_array_from_process_local_data_compat(sharding: NamedSharding,
                                               local_data,
                                               global_shape: "tuple | None"
                                               = None):
-    """``jax.make_array_from_process_local_data`` across jax versions.
-
-    The multi-process cohort-assembly primitive: each host contributes the
-    slice its devices own and jax stitches the global sharded array.  The
-    public API appeared in jax 0.4.31 (the ``global_shape`` parameter
-    became optional later); on releases without it — or without
-    multi-process support at all — a single-process topology falls back to
-    ``jax.device_put`` onto the sharding, which is exactly what the
-    primitive degenerates to when every shard is process-local.  Lives
-    next to ``make_mesh_compat`` so a jax bump has one seam to patch.
-    """
-    fn = getattr(jax, "make_array_from_process_local_data", None)
-    if fn is not None:
-        try:
-            return fn(sharding, local_data, global_shape)
-        except TypeError:       # pre-0.4.35 signature: no global_shape arg
-            if global_shape is not None:
-                raise
-            return fn(sharding, local_data)
-    if jax.process_count() != 1:
-        raise RuntimeError(
-            "this jax release has no make_array_from_process_local_data "
-            "but the topology is multi-process — upgrade jax (>= 0.4.31)")
-    return jax.device_put(local_data, sharding)
+    """``jax.make_array_from_process_local_data``: the multi-process
+    cohort-assembly primitive.  Each host contributes the slice its devices
+    own and jax stitches the global sharded array; single-process it is
+    ``jax.device_put`` onto the sharding."""
+    return jax.make_array_from_process_local_data(sharding, local_data,
+                                                  global_shape)
 
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
+    """``jax.shard_map`` with replication checking off.
 
-    jax >= 0.6 exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  Every
-    caller in this repo wants checking off (weights enter replicated but are
-    consumed per-shard), so the flag is hard-wired here.
+    Every caller in this repo wants ``check_vma=False``: weights enter
+    replicated but are consumed per-shard.
     """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # pre-0.6: the kwarg is check_rep
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,11 @@
 import os
 import sys
 
-import pytest
+# the suite runs on the CPU by design, with Pallas kernels in interpret
+# mode; chip runs go through chip_smoke.py.  Set before any jax import.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import pytest  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

@@ -47,3 +47,18 @@ def test_client_batches_are_client_distinct():
     assert data.shape == (3, 1, 4, 32)
     # different clients draw from different Markov sources
     assert not np.array_equal(data[0], data[1])
+
+
+def test_compile_cache_dir_is_env_or_fixed_repo_path():
+    """With JAX_COMPILATION_CACHE_DIR set nothing is set in code; unset,
+    the cache is the fixed, gitignored ``<repo>/.jax_cache``."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    assert compile_cache.compile_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}) is None
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.compile_cache_dir({}) == str(repo / ".jax_cache")
+    ignored = (repo / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored

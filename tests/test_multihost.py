@@ -367,7 +367,7 @@ def _spawn_workers(tmp_path, algo, spec, n_hosts=2, xla_flags=None,
     worker = tmp_path / "worker.py"
     worker.write_text(_WORKER)
     exch = str(tmp_path / "exchange") if exch is None else exch
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     if xla_flags:
         env["XLA_FLAGS"] = xla_flags
@@ -595,7 +595,7 @@ def _spawn_distributed(tmp_path, spec, xla_flags=None, timeout=600):
     worker.write_text(_DIST_WORKER)
     coord = f"127.0.0.1:{find_free_port()}"
     exch = str(tmp_path / "exchange")
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     if xla_flags:
         env["XLA_FLAGS"] = xla_flags
@@ -612,13 +612,13 @@ def _spawn_distributed(tmp_path, spec, xla_flags=None, timeout=600):
 
 def test_distributed_global_array_stitch():
     """A REAL 2-process ``jax.distributed`` topology on CPU (gloo): the
-    smoke CLI stitches a global array from process-local shards — the
-    non-fallback branch of ``make_array_from_process_local_data_compat``,
+    smoke CLI stitches a global array from process-local shards through
+    ``make_array_from_process_local_data_compat`` across processes,
     unreachable single-process — and every rank sums it identically."""
     from repro.launch.distributed import find_free_port
 
     coord = f"127.0.0.1:{find_free_port()}"
-    env = dict(os.environ, PYTHONPATH=SRC,
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2")
     procs = [subprocess.Popen(
         [sys.executable, "-m", "repro.launch.distributed",

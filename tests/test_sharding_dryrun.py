@@ -51,13 +51,27 @@ def test_moe_expert_parallel_spec():
 
 
 def test_make_mesh_compat_matches_make_mesh():
-    """The version shim must produce the same mesh jax.make_mesh would
-    (and still work if jax.make_mesh is absent, via the mesh_utils path)."""
+    """The repo's mesh helper builds the mesh jax.make_mesh would, axis
+    names and device shape alike."""
     m = sh.make_mesh_compat((1,), ("clients",))
     assert m.axis_names == ("clients",)
     assert m.devices.shape == (1,)
     m2 = sh.make_mesh_compat((1, 1), ("data", "model"))
     assert m2.axis_names == ("data", "model")
+    assert m2.devices.shape == jax.make_mesh((1, 1), ("a", "b")).devices.shape
+
+
+def test_mesh_helpers_yield_auto_axes():
+    """jax.make_mesh defaults to Explicit axes, under which slicing the
+    executor's client-sharded outputs raises ShardingTypeError; every mesh
+    the repo builds has Auto axes."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_clients_mesh, make_host_mesh
+    meshes = [sh.make_mesh_compat((1, 1), ("data", "model")),
+              make_clients_mesh(), make_host_mesh()]
+    for m in meshes:
+        assert m.axis_types == (AxisType.Auto,) * len(m.axis_names)
 
 
 def test_make_clients_mesh_spans_all_devices():
