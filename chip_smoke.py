@@ -34,13 +34,13 @@ process that finds no TPU.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import json
 import math
 import os
 import sys
 import time
+from unittest import mock
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -67,7 +67,6 @@ ROUTE_TOL = 1e-4     # max |a - b| over parameters of magnitude ~0.1-1
 COMPARE_STEPS = 1
 
 KERNEL_NAMES = ("_grouped_conv_fwd_kernel", "_kd_kl_fwd_kernel")
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class SmokeFailure(RuntimeError):
@@ -81,19 +80,6 @@ def check(ok: bool, msg: str) -> None:
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-class Compiles:
-    """XLA backend compiles and their seconds (a ``jax.monitoring``
-    listener); persistent-cache hits do not count."""
-
-    def __init__(self):
-        self.count, self.seconds = 0, 0.0
-
-    def __call__(self, event: str, duration: float, **kw) -> None:
-        if event == COMPILE_EVENT:
-            self.count += 1
-            self.seconds += duration
 
 
 # -- the task ---------------------------------------------------------------
@@ -137,20 +123,6 @@ def max_param_diff(a, b) -> float:
 
     return max(float(np.max(np.abs(np.asarray(x) - np.asarray(y))))
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
-
-
-@contextlib.contextmanager
-def wrapped(cls, name: str, wrapper):
-    """Route ``cls.name`` through ``wrapper(orig, self, *args)`` for the
-    duration of the block: how the smoke sees the arguments and outputs of
-    the round body the loop actually ran."""
-    orig = getattr(cls, name)
-    setattr(cls, name,
-            lambda self, *a, **kw: wrapper(orig, self, *a, **kw))
-    try:
-        yield
-    finally:
-        setattr(cls, name, orig)
 
 
 def abstract(tree):
@@ -255,13 +227,14 @@ def phase_main(task, data, sizes: Sizes) -> None:
     from repro.core import executor as executor_lib
 
     seen = {}
+    execute = executor_lib.VmapExecutor._execute
 
-    def record(orig, self, ctx, *args):
+    def record(self, ctx, *args):
         seen.setdefault("round", (ctx, abstract(args)))
-        return orig(self, ctx, *args)
+        return execute(self, ctx, *args)
 
     t0 = time.perf_counter()
-    with wrapped(executor_lib.VmapExecutor, "_execute", record):
+    with mock.patch.object(executor_lib.VmapExecutor, "_execute", record):
         h = run(task, data, sizes, "auto")
     wall = time.perf_counter() - t0
     tele = h.telemetry
@@ -328,9 +301,10 @@ def phase_four_chips(task, data, sizes: Sizes) -> None:
     check(len(devices) == 4, f"--four-chips needs 4 devices, found "
           f"{len(devices)}")
     placed = []
+    round_fn = executor_lib.ShardMapExecutor._sharded_round_fn
 
-    def record(orig, self, ctx, mesh):
-        fn = orig(self, ctx, mesh)
+    def record(self, ctx, mesh):
+        fn = round_fn(self, ctx, mesh)
 
         def call(*args):
             out = fn(*args)
@@ -342,8 +316,8 @@ def phase_four_chips(task, data, sizes: Sizes) -> None:
 
     t0 = time.perf_counter()
     with jax.default_matmul_precision("highest"):
-        with wrapped(executor_lib.ShardMapExecutor, "_sharded_round_fn",
-                     record):
+        with mock.patch.object(executor_lib.ShardMapExecutor,
+                               "_sharded_round_fn", record):
             hm = run(task, data, sizes,
                      executor_lib.ShardMapExecutor(strict=True), rounds=2)
         wall = time.perf_counter() - t0
@@ -394,19 +368,28 @@ def main(argv=None) -> int:
 
     log(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
         f"jax {jax.__version__}; compile cache {enable_compile_cache()}")
-    compiles = Compiles()
-    jax.monitoring.register_event_duration_secs_listener(compiles)
+    from repro.core import tracing
+
     sizes = Sizes()
     task, data = make_task(sizes)
     phases = ([phase_four_chips] if args.four_chips else
               [phase_kernels, phase_main, phase_vs_sequential])
     for phase in phases:
-        t0, c0, s0 = time.perf_counter(), compiles.count, compiles.seconds
+        t0 = time.perf_counter()
         log(f"== {phase.__name__}")
+        rec = tracing.enable()
         phase(task, data, sizes)
+        tracing.disable()
+        tally = sorted(rec.compiles_by_function().items(),
+                       key=lambda kv: -kv[1]["seconds"])
+        compiles = sum(t["compiles"] for _, t in tally)
+        seconds = sum(rec.compile_seconds(sorted(rec.compile_spans)))
+        top = ", ".join(f"{f} {t['traces']}/{t['compiles']}/"
+                        f"{t['seconds']:.1f}" for f, t in tally[:8])
         log(f"== {phase.__name__} ok, smoke wall time "
-            f"{time.perf_counter() - t0:.1f} s; {compiles.count - c0} XLA "
-            f"compiles, {compiles.seconds - s0:.1f} s of them")
+            f"{time.perf_counter() - t0:.1f} s; {compiles} XLA compiles or "
+            f"cache loads, {seconds:.1f} s tracing, lowering and compiling;"
+            f" by function (traces/compiles/s): {top}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

@@ -128,7 +128,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import client as client_lib
+from repro.core import client as client_lib, tracing
 from repro.core.algorithms import Algorithm
 from repro.core.modelzoo import ModelBundle
 from repro.data.pipeline import ClientData, ClientSlabStore, slab_rows
@@ -229,10 +229,13 @@ class RoundContext:
         # placement counters, parts recomputed — written by executors, read
         # by fl_loop logging and the regression tests
         self.telemetry: dict = {}
-        # distinct round-body input shape signatures seen so far: each new
-        # signature is exactly one XLA retrace of the round function, so
-        # ``telemetry["compile_count"] == len(round_shapes)`` counts
-        # compiled round bodies (the fixed-slot acceptance criterion)
+        # distinct round-body input shape signatures seen so far:
+        # ``telemetry["compile_count"] == len(round_shapes)`` counts the
+        # round body's signatures (the fixed-slot acceptance criterion), not
+        # compiles — it misses the precompute, gather and eval programs and
+        # cannot tell a compile from a persistent-cache load.  The tally of
+        # every jitted function's traces and compiles is
+        # ``repro.core.tracing.Recorder.compiles_by_function``.
         self.round_shapes: set = set()
 
     def note_round_shape(self, sig: tuple) -> None:
@@ -549,8 +552,10 @@ class VmapExecutor:
         fn = ctx.jit_cache.get("gather")
         if fn is None:
             # per-batch rows: leaves (K, N_max, ...) -> (K, S, B, ...)
-            fn = jax.jit(jax.vmap(lambda a, p: jax.tree_util.tree_map(
-                lambda l: l[p], a)))
+            def precompute_gather(aux, picks):
+                return jax.tree_util.tree_map(lambda l: l[picks], aux)
+
+            fn = jax.jit(jax.vmap(precompute_gather))
             ctx.jit_cache["gather"] = fn
         return fn
 
@@ -672,41 +677,52 @@ class VmapExecutor:
         full = None
         aux_full = None
         if ctx.has_precompute or ctx.has_finalize:
-            full = _pad_full_data(
-                client_data, cache=ctx.jit_cache.setdefault("full_data", {}),
-                cohort_key=(tuple(client_ids)
-                            if client_ids is not None else None),
-                k_pad=k_pad, n_pad=ctx.pad_rows)
+            with tracing.span("fl.exec.full_shards"):
+                full = _pad_full_data(
+                    client_data,
+                    cache=ctx.jit_cache.setdefault("full_data", {}),
+                    cohort_key=(tuple(client_ids)
+                                if client_ids is not None else None),
+                    k_pad=k_pad, n_pad=ctx.pad_rows)
         if ctx.has_precompute:
+            # rows the teacher forward runs over, against the real ones
+            tracing.count("precompute_rows", full[0].shape[0]
+                          * full[0].shape[1])
+            tracing.count("precompute_rows_real",
+                          sum(d.n for d in client_data))
             parts_spec = (ctx.algo.precompute_parts(payload)
                           if client_ids is not None else None)
-            if parts_spec is not None:
-                aux_full = self._incremental_aux(ctx, payload, parts_spec,
-                                                 client_ids, client_data,
-                                                 full)
-            else:
-                # dispatch the (async) teacher forward FIRST: it needs no
-                # batch picks, so the device crunches it while the host
-                # materializes and pads the round's batches below
-                aux_full = self._precompute_fn(ctx)(payload, *full)
+            with tracing.span("fl.exec.precompute"):
+                if parts_spec is not None:
+                    aux_full = self._incremental_aux(
+                        ctx, payload, parts_spec, client_ids, client_data,
+                        full)
+                else:
+                    # dispatch the (async) teacher forward FIRST: it needs
+                    # no batch picks, so the device crunches it while the
+                    # host materializes and pads the round's batches below
+                    aux_full = self._precompute_fn(ctx)(payload, *full)
 
-        mats = ([client_from_picks(d, p)
-                 for d, p in zip(client_data, picks)]
-                if picks is not None else
-                [materialize_client(rng, d, ctx.batch_size, ctx.epochs,
-                                    ctx.max_batches) for d in client_data])
-        xs, ys, ex_mask, picks, step_mask = _pad_and_stack(
-            mats, k_pad=k_pad, s_pad=ctx.pad_steps, b_pad=ctx.pad_batch)
-        states_real = tree_stack(client_states)
-        states_stacked = _pad_clients_axis(states_real, k_pad)
-        aux = (self._gather_fn(ctx)(aux_full, picks)
-               if ctx.has_precompute else ())
+        with tracing.span("fl.exec.picks"):
+            mats = ([client_from_picks(d, p)
+                     for d, p in zip(client_data, picks)]
+                    if picks is not None else
+                    [materialize_client(rng, d, ctx.batch_size, ctx.epochs,
+                                        ctx.max_batches)
+                     for d in client_data])
+        with tracing.span("fl.exec.stack"):
+            xs, ys, ex_mask, picks, step_mask = _pad_and_stack(
+                mats, k_pad=k_pad, s_pad=ctx.pad_steps, b_pad=ctx.pad_batch)
+            states_real = tree_stack(client_states)
+            states_stacked = _pad_clients_axis(states_real, k_pad)
         ctx.note_round_shape(("round", ctx.telemetry["round_body"])
                              + tuple(xs.shape))
-
-        params_padded, mloss_padded = self._execute(
-            ctx, global_params, payload, states_stacked, xs, ys, ex_mask,
-            aux, step_mask)
+        with tracing.span("fl.exec.body"):
+            aux = (self._gather_fn(ctx)(aux_full, picks)
+                   if ctx.has_precompute else ())
+            params_padded, mloss_padded = self._execute(
+                ctx, global_params, payload, states_stacked, xs, ys,
+                ex_mask, aux, step_mask)
         # drop phantom slots before anything downstream sees them
         params_stacked = (jax.tree_util.tree_map(lambda l: l[:k],
                                                  params_padded)
@@ -731,9 +747,13 @@ class VmapExecutor:
         uploads = [{"params": p, **e} for p, e in per_client]
         new_states = (_tree_unstack_jit(new_states_stacked, k)
                       if ctx.has_state_update else list(client_states))
-        return RoundResult(uploads, [float(m.n) for m in mats],
-                           mloss if ctx.deferred
-                           else np.asarray(mloss).astype(float).tolist(),
+        if ctx.deferred:
+            losses = mloss
+        else:
+            with tracing.span("fl.exec.losses"):
+                losses = np.asarray(mloss).astype(float).tolist()
+            tracing.count("host_syncs")
+        return RoundResult(uploads, [float(m.n) for m in mats], losses,
                            new_states)
 
 

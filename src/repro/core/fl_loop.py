@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.paper import PaperTask
-from repro.core import executor as executor_lib
+from repro.core import executor as executor_lib, tracing
 from repro.core.algorithms import Algorithm, FedGen
 from repro.core.distillation import accuracy, cross_entropy
 from repro.core.modelzoo import ModelBundle, make_model
@@ -92,12 +92,14 @@ def evaluate(model: ModelBundle, params: Any, x: np.ndarray, y: np.ndarray,
              batch: int = 256) -> tuple[float, float]:
     accs, losses, ns = [], [], []
     apply = _cached_apply(model)
-    for i in range(0, len(y), batch):
-        xb, yb = jnp.asarray(x[i:i + batch]), jnp.asarray(y[i:i + batch])
-        logits = apply(params, xb)
-        accs.append(float(accuracy(logits, yb)) * len(yb))
-        losses.append(float(cross_entropy(logits, yb)) * len(yb))
-        ns.append(len(yb))
+    with tracing.span("fl.evaluate"):
+        for i in range(0, len(y), batch):
+            xb, yb = jnp.asarray(x[i:i + batch]), jnp.asarray(y[i:i + batch])
+            logits = apply(params, xb)
+            accs.append(float(accuracy(logits, yb)) * len(yb))
+            losses.append(float(cross_entropy(logits, yb)) * len(yb))
+            ns.append(len(yb))
+    tracing.count("host_syncs", 2 * len(ns))    # the two float()s a batch
     n = sum(ns)
     return sum(accs) / n, sum(losses) / n
 
@@ -308,95 +310,107 @@ def run_federated(task: PaperTask, algo: Algorithm,
                  "algo": algo.name})
 
     for t in range(start_round, rounds):
-        t0 = time.time()
-        jrng, krng = jax.random.split(jrng)
-        sampled = data.sample_cohort(rng, n_sample)
-        payload = algo.round_payload(server, krng)
+        with tracing.round_span(t + 1):
+            t0 = time.perf_counter()
+            jrng, krng = jax.random.split(jrng)
+            sampled = data.sample_cohort(rng, n_sample)
+            payload = algo.round_payload(server, krng)
 
-        cids = [int(k) for k in sampled]
-        if multihost and injector is not None:
-            # placement-aware fault tolerance: a crashed/deadline-missing
-            # HOST is a correlated fault over its whole owned slice;
-            # quorum counts surviving hosts' validated uploads and retry
-            # re-dispatches the absent slice through the exchange
-            uploads, weights, local_losses = _multihost_fault_round(
-                exec_, ctx, pop, server, payload, client_states, rng,
-                cids, injector, policy, t, dead_hosts)
-        elif multihost:
-            # train only the owned slice, exchange uploads, aggregate the
-            # identical full-cohort update on every host
-            uploads, weights, local_losses = _multihost_round(
-                ctx, exec_, pop, server["global"], payload, client_states,
-                cids, rng, t)
-        elif injector is None:
-            if pop is not None:
-                # the cohort must not thrash the warm tier against itself
-                # while the round materializes / trains it
-                pop.pin(cids)
-            result = exec_.run_round(
-                ctx, server["global"], payload,
-                [client_states[k] for k in cids],
-                [data.clients[k] for k in cids], rng,
-                client_ids=cids)
-            uploads, weights = result.uploads, result.weights
-            local_losses = result.local_losses
-            for k, new_state in zip(cids, result.client_states):
-                client_states[k] = new_state
-        else:
-            if pop is not None:
-                pop.pin(cids)
-            uploads, weights, local_losses = _fault_tolerant_round(
-                exec_, ctx, server, payload, client_states, data, rng,
-                cids, injector, policy)
-        if verbose and t == 0:
-            # which route actually ran (the shard_map executor may degrade
-            # to vmap on a single device — see RoundContext.telemetry)
-            tele = ctx.telemetry
-            print(f"[{algo.name}] executor route: "
-                  f"{tele.get('route', exec_.name)}"
-                  + (f" ({tele['n_devices']} devices, cohort "
-                     f"{tele['cohort']} padded to {tele['padded_to']})"
-                     if "padded_to" in tele else ""))
-        if pop is not None and not multihost:
-            pop.unpin(cids)
-            ctx.telemetry["population"] = pop.stats()
+            cids = [int(k) for k in sampled]
+            if multihost and injector is not None:
+                # placement-aware fault tolerance: a crashed/deadline-
+                # missing HOST is a correlated fault over its whole owned
+                # slice; quorum counts surviving hosts' validated uploads
+                # and retry re-dispatches the absent slice through the
+                # exchange
+                uploads, weights, local_losses = _multihost_fault_round(
+                    exec_, ctx, pop, server, payload, client_states, rng,
+                    cids, injector, policy, t, dead_hosts)
+            elif multihost:
+                # train only the owned slice, exchange uploads, aggregate
+                # the identical full-cohort update on every host
+                uploads, weights, local_losses = _multihost_round(
+                    ctx, exec_, pop, server["global"], payload,
+                    client_states, cids, rng, t)
+            elif injector is None:
+                if pop is not None:
+                    # the cohort must not thrash the warm tier against
+                    # itself while the round materializes / trains it
+                    pop.pin(cids)
+                with tracing.span("fl.run_round"):
+                    result = exec_.run_round(
+                        ctx, server["global"], payload,
+                        [client_states[k] for k in cids],
+                        [data.clients[k] for k in cids], rng,
+                        client_ids=cids)
+                uploads, weights = result.uploads, result.weights
+                local_losses = result.local_losses
+                for k, new_state in zip(cids, result.client_states):
+                    client_states[k] = new_state
+            else:
+                if pop is not None:
+                    pop.pin(cids)
+                uploads, weights, local_losses = _fault_tolerant_round(
+                    exec_, ctx, server, payload, client_states, data, rng,
+                    cids, injector, policy)
+            if verbose and t == 0:
+                # which route actually ran (the shard_map executor may
+                # degrade to vmap on a single device — see
+                # RoundContext.telemetry)
+                tele = ctx.telemetry
+                print(f"[{algo.name}] executor route: "
+                      f"{tele.get('route', exec_.name)}"
+                      + (f" ({tele['n_devices']} devices, cohort "
+                         f"{tele['cohort']} padded to {tele['padded_to']})"
+                         if "padded_to" in tele else ""))
+            if pop is not None and not multihost:
+                pop.unpin(cids)
+                ctx.telemetry["population"] = pop.stats()
 
-        if not uploads:
-            # every client of the cohort crashed/was rejected through all
-            # retries: hold the global fixed rather than aggregate nothing
-            ctx.telemetry["faults"]["skipped_rounds"] += 1
-        else:
-            if dp is not None:
-                from repro.core import privacy
-                uploads = privacy.privatize_uploads(uploads, server["global"],
-                                                    dp, t)
-            server = algo.server_update(server, uploads, weights, model,
-                                        val_batch, n_clients=data.n_clients)
-            if dp is not None:
-                from repro.core import privacy
-                server["global"] = privacy.noise_aggregate(server["global"],
-                                                           dp, len(uploads), t)
+            if not uploads:
+                # every client of the cohort crashed/was rejected through
+                # all retries: hold the global fixed rather than aggregate
+                # nothing
+                ctx.telemetry["faults"]["skipped_rounds"] += 1
+            else:
+                if dp is not None:
+                    from repro.core import privacy
+                    uploads = privacy.privatize_uploads(
+                        uploads, server["global"], dp, t)
+                with tracing.span("fl.server_update"):
+                    server = algo.server_update(server, uploads, weights,
+                                                model, val_batch,
+                                                n_clients=data.n_clients)
+                if dp is not None:
+                    from repro.core import privacy
+                    server["global"] = privacy.noise_aggregate(
+                        server["global"], dp, len(uploads), t)
 
-        if (t + 1) % eval_every == 0 or t == rounds - 1:
-            acc, loss = evaluate(model, server["global"], data.test_x, data.test_y)
-        else:
-            acc, loss = (records[-1].test_acc, records[-1].test_loss) if records else (0.0, 0.0)
-        records.append(RoundRecord(t + 1, acc, loss,
-                                   float(np.mean(local_losses)) if local_losses
-                                   else 0.0,
-                                   time.time() - t0,
-                                   sampled=tuple(int(k) for k in sampled)))
-        if checkpoint_dir is not None and (
-                (t + 1) % checkpoint_every == 0 or t == rounds - 1):
-            _save_checkpoint(checkpoint_dir, t + 1, algo, server, jrng, rng,
-                             injector, records, client_states, data.n_clients,
-                             ftel=ctx.telemetry.get("faults"),
-                             host=ckpt_host)
-        if round_callback is not None:
-            round_callback(t + 1, server, model)
-        if verbose:
-            print(f"[{algo.name}] round {t+1:3d}/{rounds} "
-                  f"acc={acc:.4f} loss={loss:.4f} local={np.mean(local_losses):.4f}")
+            if (t + 1) % eval_every == 0 or t == rounds - 1:
+                acc, loss = evaluate(model, server["global"], data.test_x,
+                                     data.test_y)
+            elif records:
+                acc, loss = records[-1].test_acc, records[-1].test_loss
+            else:
+                acc, loss = 0.0, 0.0
+            records.append(RoundRecord(
+                t + 1, acc, loss,
+                float(np.mean(local_losses)) if local_losses else 0.0,
+                time.perf_counter() - t0,
+                sampled=tuple(int(k) for k in sampled)))
+            if checkpoint_dir is not None and (
+                    (t + 1) % checkpoint_every == 0 or t == rounds - 1):
+                _save_checkpoint(checkpoint_dir, t + 1, algo, server, jrng,
+                                 rng, injector, records, client_states,
+                                 data.n_clients,
+                                 ftel=ctx.telemetry.get("faults"),
+                                 host=ckpt_host)
+            if round_callback is not None:
+                round_callback(t + 1, server, model)
+            if verbose:
+                print(f"[{algo.name}] round {t+1:3d}/{rounds} "
+                      f"acc={acc:.4f} loss={loss:.4f} "
+                      f"local={np.mean(local_losses):.4f}")
 
     # paper Fig.2-style: accuracy of the last trained LOCAL model
     if uploads:
@@ -1246,7 +1260,7 @@ def _run_async(task: PaperTask, algo: Algorithm, data: FederatedData,
     if start_round == 0:
         dispatch_wave(n_sample)
     for t in range(start_round, rounds):
-        t0 = time.time()
+        t0 = time.perf_counter()
         if injector is None:
             completions = sim.pop_batch(b)
         else:
@@ -1260,7 +1274,7 @@ def _run_async(task: PaperTask, algo: Algorithm, data: FederatedData,
                              evaluate(model, server["global"],
                                       data.test_x, data.test_y))
                 records.append(RoundRecord(
-                    t + 1, acc, loss, 0.0, time.time() - t0,
+                    t + 1, acc, loss, 0.0, time.perf_counter() - t0,
                     sim_time=sim.now, version=version))
                 if wants_refill(t):
                     dispatch_wave(min(b, data.n_clients - len(in_flight)))
@@ -1333,7 +1347,7 @@ def _run_async(task: PaperTask, algo: Algorithm, data: FederatedData,
                          if records else (0.0, 0.0))
         records.append(RoundRecord(
             t + 1, acc, loss, float(np.mean(local_losses)),
-            time.time() - t0, sim_time=sim.now, version=version,
+            time.perf_counter() - t0, sim_time=sim.now, version=version,
             mean_staleness=float(np.mean(staleness)),
             sampled=tuple(c.client for c in completions)))
         if wants_refill(t) and not refilled:

@@ -46,6 +46,7 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
 from repro.core.distillation import ensemble_average
 
 STALENESS_SCHEMES = ("constant", "polynomial", "fedgkd")
@@ -92,6 +93,7 @@ def first_nonfinite_path(tree: Any) -> Optional[str]:
         arr = jnp.asarray(leaf)
         if not jnp.issubdtype(arr.dtype, jnp.inexact):
             continue
+        tracing.count("host_syncs")
         if not bool(jnp.all(jnp.isfinite(arr.astype(jnp.float32)))):
             return "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                             for p in path)
@@ -191,6 +193,7 @@ def _trees_identical(a: Any, b: Any) -> bool:
         x, y = jnp.asarray(x), jnp.asarray(y)
         if x.shape != y.shape or x.dtype != y.dtype:
             return False
+        tracing.count("host_syncs")
         if not bool(jnp.all(x == y)):
             return False
     return True
@@ -221,14 +224,15 @@ class ModelBuffer:
         self._next_version = 0
 
     def push(self, params: Any) -> bool:
-        bad = first_nonfinite_path(params)
-        if bad is not None:
-            raise ValueError(
-                f"ModelBuffer.push: non-finite teacher candidate at "
-                f"leaf {bad!r} — rejected updates must be quarantined "
-                f"before they reach the KD buffer")
-        if self._buf and _trees_identical(params, self._buf[-1]):
-            return False
+        with tracing.span("fl.server.push"):
+            bad = first_nonfinite_path(params)
+            if bad is not None:
+                raise ValueError(
+                    f"ModelBuffer.push: non-finite teacher candidate at "
+                    f"leaf {bad!r} — rejected updates must be quarantined "
+                    f"before they reach the KD buffer")
+            if self._buf and _trees_identical(params, self._buf[-1]):
+                return False
         self._buf.append(params)
         self._versions.append(self._next_version)
         self._next_version += 1

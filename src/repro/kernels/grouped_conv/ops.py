@@ -53,8 +53,9 @@ def _pallas_fwd(x, w, stride, padding, interpret):
     xp = jnp.pad(x, ((0, 0), (0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
     xp = _pad_axis(xp, 4, _LANES)
     wp = _pad_axis(_pad_axis(w, 3, _LANES), 4, _LANES)
-    out = K.grouped_conv_fwd(xp, wp, stride=stride, oh=oh, ow=ow,
-                             interpret=interpret)
+    with jax.named_scope("grouped_conv_fwd"):
+        out = K.grouped_conv_fwd(xp, wp, stride=stride, oh=oh, ow=ow,
+                                 interpret=interpret)
     return out[..., :cout]
 
 

@@ -31,9 +31,10 @@ def _fwd_impl(teacher_logits, student_logits, temperature, block_rows,
               block_vocab, interpret):
     lt = _pad2(teacher_logits, block_rows, block_vocab, _PAD)
     ls = _pad2(student_logits, block_rows, block_vocab, _PAD)
-    kl, lse_t, lse_s = K.kd_kl_fwd(
-        lt, ls, temperature=temperature, block_rows=block_rows,
-        block_vocab=block_vocab, interpret=interpret)
+    with jax.named_scope("kd_kl_fwd"):
+        kl, lse_t, lse_s = K.kd_kl_fwd(
+            lt, ls, temperature=temperature, block_rows=block_rows,
+            block_vocab=block_vocab, interpret=interpret)
     return kl[: teacher_logits.shape[0]], lse_t, lse_s
 
 
@@ -60,9 +61,10 @@ def _bwd(temperature, block_rows, block_vocab, interpret, res, g):
     ltp = _pad2(lt, block_rows, block_vocab, _PAD)
     lsp = _pad2(ls, block_rows, block_vocab, _PAD)
     gp = jnp.pad(g, (0, (-t) % block_rows))
-    dls = K.kd_kl_bwd(ltp, lsp, lse_t, lse_s, gp.astype(jnp.float32),
-                      temperature=temperature, block_rows=block_rows,
-                      block_vocab=block_vocab, interpret=interpret)
+    with jax.named_scope("kd_kl_bwd"):
+        dls = K.kd_kl_bwd(ltp, lsp, lse_t, lse_s, gp.astype(jnp.float32),
+                          temperature=temperature, block_rows=block_rows,
+                          block_vocab=block_vocab, interpret=interpret)
     # temperature² from fwd's /(inv²) cancels one 1/temp of d(l/temp): net ·temp
     dls = dls[:t, :v] * temperature * temperature
     return jnp.zeros_like(lt), dls.astype(ls.dtype)

@@ -89,3 +89,29 @@ def test_client_batched_conv_compiles(one_chip, name, vjp):
           if vjp else fwd)
     text = _compiled_text(fn, xs, ws, sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+def _kernel_op_names(text: str) -> list[str]:
+    """The ``op_name`` of every Pallas call in a compiled program's text."""
+    return [line.split('op_name="')[1].split('"')[0]
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def test_kernel_calls_carry_their_named_scope(one_chip):
+    """The scopes in ``ops.py`` name each Pallas call on the chip's trace
+    (the forward kernels under ``jvp(<scope>)``, the KD-KL backward under
+    ``transpose(jvp(kd_kl_bwd))``) in place of a bare ``jvp()``."""
+    kd = _compiled_text(
+        jax.grad(lambda t, s: jnp.sum(kd_ops.kd_kl_loss(t, s,
+                                                        interpret=False)),
+                 argnums=1), (256, 10), (256, 10), sharding=one_chip)
+    xs, ws, stride = CONV_SHAPES["r8_stem"]
+    conv = _compiled_text(
+        jax.grad(lambda x, w: jnp.sum(conv_ops.client_batched_conv(
+            x, w, stride=stride, use_pallas=True, interpret=False) ** 2),
+            argnums=(0, 1)), xs, ws, sharding=one_chip)
+    assert sorted(n.split("/", 1)[1] for n in _kernel_op_names(kd)) == [
+        "jvp(kd_kl_fwd)/pallas_call", "transpose(jvp(kd_kl_bwd))/pallas_call"]
+    assert [n.split("/", 1)[1] for n in _kernel_op_names(conv)] == [
+        "jvp(grouped_conv_fwd)/pallas_call"]
