@@ -113,10 +113,6 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
     return -jnp.sum(ll * valid) / jnp.maximum(1.0, jnp.sum(valid))
 
 
-def accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:
-    return jnp.mean((jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
-
-
 # ---------------------------------------------------------------------------
 # global-model ensembling (server side)
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ import pytest
 
 from repro.configs.paper import TOY
 from repro.core import algorithms, executor as ex, fl_loop
+from repro.core.distillation import cross_entropy
 from repro.data.pipeline import (ClientData, ClientSlabStore, FederatedData,
                                  SLAB_QUANT, batch_iterator, slab_rows)
 from repro.data.synthetic import SyntheticTabularTask
@@ -352,6 +353,53 @@ def test_evaluate_apply_cache(tiny_setup):
     assert fl_loop._APPLY_CACHE.get(model2.apply) is fn
 
 
+def _plain_evaluate(model, params, x, y, batch=256):
+    """The per-batch loop ``fl_loop.evaluate`` replaced: one ``apply`` and
+    two host reads a batch; the loss is each batch's mean weighted by its
+    rows."""
+    apply = jax.jit(model.apply)
+    correct, loss = 0, 0.0
+    for i in range(0, len(y), batch):
+        logits = apply(params, jnp.asarray(x[i:i + batch]))
+        yb = y[i:i + batch]
+        correct += int(np.sum(np.argmax(np.asarray(logits), -1) == yb))
+        loss += float(cross_entropy(logits, jnp.asarray(yb))) * len(yb)
+    return correct / len(y), loss / len(y)
+
+
+def _eval_bundle(name):
+    if name == "mlp":
+        task = TOY
+        gen = SyntheticTabularTask(task.num_classes, dim=task.feat_dim,
+                                   seed=0)
+        return task, fl_loop.make_model(task), gen
+    from repro.configs.paper import CIFAR10
+    from repro.data.synthetic import SyntheticImageTask
+    task = dataclasses.replace(CIFAR10, image_hw=16)
+    return (task, fl_loop.make_model(task, width=8),
+            SyntheticImageTask(task.num_classes, hw=16, seed=0))
+
+
+@pytest.mark.parametrize("n", [100, 512, 300],
+                         ids=["under_batch", "multiple", "ragged"])
+@pytest.mark.parametrize("bundle", ["mlp", "resnet8"])
+def test_evaluate_matches_plain_batch_loop(bundle, n):
+    """One program over the padded resident test set gives the plain
+    loop's accuracy exactly and its loss to float32 rounding, with
+    ignore-labelled rows counted in ``n`` as before."""
+    task, model, gen = _eval_bundle(bundle)
+    if bundle == "resnet8":
+        assert model.client_batched
+    params = model.init(jax.random.PRNGKey(3))
+    x, y = gen.generate(n, seed=7)
+    y = np.where(np.arange(n) % 7 == 3, -1, y)
+    acc, loss = fl_loop.evaluate(model, params, x, y)
+    ref_acc, ref_loss = _plain_evaluate(model, params, x, y)
+    assert acc == ref_acc
+    assert loss == pytest.approx(ref_loss, rel=1e-6)
+    assert fl_loop.evaluate(model, params, x, y) == (acc, loss)
+
+
 # --- slab layout / placement store (single device is enough) ----------------
 
 def test_slab_rows_quantization():
@@ -634,6 +682,54 @@ def test_shard_map_precompute_matches_no_aux_baseline(tiny_setup):
                                   executor=ex.ShardMapExecutor(strict=True),
                                   precompute=True)
         assert _max_param_diff(base.final_params, h.final_params) < 1e-5, name
+
+
+def _check_mesh_evaluate():
+    """``run_federated(executor="shard_map")`` leaves the global model on
+    the ("clients",) mesh and evaluates it there: every round's test
+    accuracy and loss match the vmap route's to 1e-5, over a test set
+    with a ragged last batch."""
+    task = dataclasses.replace(TOY, n_clients=len(RAGGED_SIZES_8),
+                               participation=1.0, batch_size=64, rounds=2,
+                               local_epochs=1)
+    data = _ragged_data(task, RAGGED_SIZES_8)
+    gen = SyntheticTabularTask(task.num_classes, dim=task.feat_dim, seed=0)
+    data = dataclasses.replace(data, **dict(zip(
+        ("test_x", "test_y"), gen.generate(300, seed=998))))
+    hv = fl_loop.run_federated(task, algorithms.make("fedavg"), data,
+                               seed=0, executor="vmap")
+    hm = fl_loop.run_federated(task, algorithms.make("fedavg"), data,
+                               seed=0, executor="shard_map")
+    leaf = jax.tree_util.tree_leaves(hm.final_params)[0]
+    assert len(leaf.devices()) == len(jax.devices()) > 1
+    for rv, rm in zip(hv.records, hm.records, strict=True):
+        assert abs(rv.test_acc - rm.test_acc) < 1e-5
+        assert abs(rv.test_loss - rm.test_loss) < 1e-5
+    assert fl_loop.evaluate(fl_loop.make_model(task), hm.final_params,
+                            data.test_x, data.test_y) == (
+        hm.records[-1].test_acc, hm.records[-1].test_loss)
+
+
+@multidevice
+def test_shard_map_evaluates_the_global_on_the_mesh():
+    _check_mesh_evaluate()
+
+
+def test_shard_map_evaluates_the_global_on_the_mesh_subprocess():
+    """The same check on 8 forced host devices when the main pytest
+    process has one."""
+    if len(jax.devices()) >= 2:
+        pytest.skip("the in-process test covers the mesh")
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, PYTHONPATH=os.path.join(here, "..", "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    code = (f"import sys; sys.path.insert(0, {here!r}); "
+            "import test_executor as t; t._check_mesh_evaluate(); "
+            "print('MESH_EVAL_OK')")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "MESH_EVAL_OK" in out.stdout
 
 
 # --- subprocess smoke: keeps the mesh route alive on single-device boxes ----

@@ -142,17 +142,61 @@ def _inexact_leaves(params) -> int:
 
 @pytest.mark.parametrize("algo", ["fedavg", "fedgkd"])
 def test_host_syncs_are_the_reads_each_site_makes(algo, recorder):
-    """A round reads the losses once and ``evaluate`` two numbers a test
-    batch; FedGKD's teacher buffer also checks each inexact leaf of the
-    new global for non-finite values, then reads one comparison to find
-    it differs from the buffer's head."""
+    """A round reads the losses once and ``evaluate`` its per-batch
+    results once, whatever the number of test batches; FedGKD's teacher
+    buffer also checks each inexact leaf of the new global for non-finite
+    values, then reads one comparison to find it differs from the
+    buffer's head."""
     task = _task(0.5)
     h = fl_loop.run_federated(task, algorithms.make(algo), _data(task),
                               seed=0, executor="vmap")
-    per_round = 1 + 2 * math.ceil(N_TEST / EVAL_BATCH)
+    assert math.ceil(N_TEST / EVAL_BATCH) > 1
+    per_round = 1 + 1
     if algo == "fedgkd":
         per_round += _inexact_leaves(h.final_params) + 1
     assert recorder.counter("host_syncs", [1, 2]) == [per_round] * 2
+
+
+def test_eval_uploads_the_test_set_once(recorder):
+    """The first round uploads the test set, later rounds and the final
+    local-model evaluation reuse it; another host array uploads again,
+    and the resident copy goes with the host array."""
+    task = _task(0.5)
+    data = _data(task)
+    fl_loop.run_federated(task, algorithms.make("fedavg"), data, seed=0,
+                          executor="vmap")
+    assert recorder.counter("eval_uploads", [1, 2]) == [1, 0]
+    assert recorder.counter("eval_uploads", [0]) == [0]
+    model = fl_loop.make_model(task)
+    params = model.init(jax.random.PRNGKey(0))
+    other_x = data.test_x.copy()
+    first = fl_loop.evaluate(model, params, data.test_x, data.test_y)
+    assert recorder.counter("eval_uploads", [0]) == [0]
+    assert fl_loop.evaluate(model, params, other_x, data.test_y) == first
+    assert recorder.counter("eval_uploads", [0]) == [1]
+    keys = [k for k in fl_loop._TEST_SETS if k[0] == id(other_x)]
+    assert len(keys) == 1
+    del other_x
+    assert keys[0] not in fl_loop._TEST_SETS
+
+
+def test_resident_test_sets_are_a_bounded_lru(recorder):
+    """At most ``_TEST_SETS_MAX`` test sets stay on the device; the least
+    recently evaluated goes first."""
+    task = _task(0.5)
+    data = _data(task)
+    model = fl_loop.make_model(task)
+    params = model.init(jax.random.PRNGKey(0))
+    xs = [data.test_x.copy() for _ in range(fl_loop._TEST_SETS_MAX + 1)]
+    for x in xs:
+        fl_loop.evaluate(model, params, x, data.test_y)
+    fl_loop.evaluate(model, params, xs[1], data.test_y)
+    assert recorder.counter("eval_uploads", [0]) == [len(xs)]
+    held = {k[0] for k in fl_loop._TEST_SETS}
+    assert id(xs[0]) not in held
+    assert {id(x) for x in xs[1:]} <= held
+    fl_loop.evaluate(model, params, xs[1], data.test_y)
+    assert recorder.counter("eval_uploads", [0]) == [len(xs)]
 
 
 def test_only_new_shapes_trace(recorder):
