@@ -2,8 +2,9 @@
 """Smoke test of the FedGKD round on a TPU: the quickest proof that the
 system still starts on the chip.
 
-    python chip_smoke.py               # one chip: the main path
-    python chip_smoke.py --four-chips  # four chips: the shard_map route only
+    python chip_smoke.py                  # one chip: the main path
+    python chip_smoke.py --four-chips     # four chips: the shard_map route only
+    python chip_smoke.py --tiny-imagenet  # one chip: ResNet-50, one round
 
 One chip drives ``run_federated`` through its normal entry on the paper's
 CIFAR-10 task with ResNet-8 at its published width (16, 32x32 inputs, 10
@@ -21,6 +22,12 @@ classes, batch 64), a cohort of K=4 of 20 clients, ``fedgkd`` and
 
 ``--four-chips`` runs ``ShardMapExecutor(strict=True)`` over four chips on
 the same task and checks it against the single-chip vmap route.
+
+``--tiny-imagenet`` drives one FedGKD round of the paper's Tiny-ImageNet
+task through ``run_federated`` at its published sizes (ResNet-50 with
+GroupNorm, 64x64x3 inputs, 200 classes, 90,000 synthetic examples over 20
+clients, K=4, 70 local steps of 64 a client) and prints the route, the
+fullest device's memory peak and the local loss.
 
 Agreement checks run under ``jax.default_matmul_precision("highest")``, so
 the tolerances below bound float32 summation order, not bf16 passes; the
@@ -227,14 +234,18 @@ def phase_main(task, data, sizes: Sizes) -> None:
     from repro.core import executor as executor_lib
 
     seen = {}
-    execute = executor_lib.VmapExecutor._execute
+    round_fn = executor_lib.VmapExecutor._round_fn
 
-    def record(self, ctx, *args):
-        seen.setdefault("round", (ctx, abstract(args)))
-        return execute(self, ctx, *args)
+    def record(self, ctx):
+        fn = round_fn(self, ctx)
+
+        def call(*args, **kwargs):
+            seen.setdefault("round", (ctx, abstract(args), kwargs))
+            return fn(*args, **kwargs)
+        return call
 
     t0 = time.perf_counter()
-    with mock.patch.object(executor_lib.VmapExecutor, "_execute", record):
+    with mock.patch.object(executor_lib.VmapExecutor, "_round_fn", record):
         h = run(task, data, sizes, "auto")
     wall = time.perf_counter() - t0
     tele = h.telemetry
@@ -252,9 +263,9 @@ def phase_main(task, data, sizes: Sizes) -> None:
     check(all(math.isfinite(v) for v in losses), f"non-finite loss {losses}")
     check(losses[-1] < losses[0], f"loss did not fall: {losses}")
 
-    ctx, args = seen["round"]
+    ctx, args, kwargs = seen["round"]
     text = executor_lib.VmapExecutor()._round_fn(ctx).lower(
-        *args, ctx.lr).as_text()
+        *args, **kwargs).as_text()
     found = {n: f'kernel_name = "{n}"' in text for n in KERNEL_NAMES}
     log(f"round body HLO: tpu_custom_call={'tpu_custom_call' in text} "
         f"kernels={found}")
@@ -350,11 +361,66 @@ def phase_four_chips(task, data, sizes: Sizes) -> None:
     check(diff <= ROUTE_TOL, "shard_map disagrees with the vmap route")
 
 
+def make_tiny_imagenet_task(sizes: Sizes):
+    """The paper's Tiny-ImageNet task at its published sizes, one round."""
+    from repro.configs.paper import TINY_IMAGENET
+    from repro.core import fl_loop
+
+    task = dataclasses.replace(TINY_IMAGENET, rounds=1)
+    t0 = time.perf_counter()
+    data = fl_loop.make_federated_data(task, alpha=sizes.alpha,
+                                       seed=sizes.seed, n_test=10_000)
+    log(f"task: {task.name} model={task.model} {task.image_hw}x"
+        f"{task.image_hw}x3 classes={task.num_classes} batch="
+        f"{task.batch_size} clients={task.n_clients} K={cohort(task)} "
+        f"local steps={tiny_imagenet_steps(task)} train={task.train_size} "
+        f"test=10000; data {time.perf_counter() - t0:.1f} s")
+    return task, data
+
+
+def tiny_imagenet_steps(task) -> int:
+    """One mean local epoch: train_size / n_clients / batch_size = 70."""
+    return round(task.train_size / task.n_clients / task.batch_size)
+
+
+def phase_tiny_imagenet(task, data, sizes: Sizes) -> None:
+    """One FedGKD round of ResNet-50 on the Tiny-ImageNet task at its
+    published sizes, through ``run_federated`` and ``executor="auto"``."""
+    import jax
+
+    from repro.core import algorithms, fl_loop
+
+    t0 = time.perf_counter()
+    h = fl_loop.run_federated(
+        task, algorithms.make("fedgkd", gamma=task.gamma,
+                              buffer_m=task.buffer_m),
+        data, seed=sizes.seed, width=64, executor="auto",
+        max_batches_per_client=tiny_imagenet_steps(task))
+    wall = time.perf_counter() - t0
+    tele = h.telemetry
+    n_params = sum(leaf.size for leaf in jax.tree.leaves(h.final_params))
+    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in jax.devices())
+    r = h.records[0]
+    log(f"route={tele.get('route')} round_body={tele.get('round_body')} "
+        f"params={n_params} memory_peak_bytes={peak} "
+        f"mean_local_loss={r.mean_local_loss!r} test_loss={r.test_loss!r}")
+    log(f"smoke wall time, one round with its compiles (not a benchmark): "
+        f"{wall:.1f} s")
+    check(tele.get("route") == "vmap", f"auto resolved to {tele.get('route')}")
+    check(tele.get("round_body") == "client_batched",
+          f"round body is {tele.get('round_body')}")
+    check(math.isfinite(r.mean_local_loss), "non-finite local loss")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the shard_map route over four chips "
                          "and its one-chip vmap comparison")
+    ap.add_argument("--tiny-imagenet", action="store_true",
+                    help="run only one FedGKD round of ResNet-50 on the "
+                         "Tiny-ImageNet task at its published sizes")
     args = ap.parse_args(argv)
 
     import jax
@@ -371,9 +437,13 @@ def main(argv=None) -> int:
     from repro.core import tracing
 
     sizes = Sizes()
-    task, data = make_task(sizes)
-    phases = ([phase_four_chips] if args.four_chips else
-              [phase_kernels, phase_main, phase_vs_sequential])
+    if args.tiny_imagenet:
+        task, data = make_tiny_imagenet_task(sizes)
+        phases = [phase_tiny_imagenet]
+    else:
+        task, data = make_task(sizes)
+        phases = ([phase_four_chips] if args.four_chips else
+                  [phase_kernels, phase_main, phase_vs_sequential])
     for phase in phases:
         t0 = time.perf_counter()
         log(f"== {phase.__name__}")

@@ -1,8 +1,8 @@
 """The paper's own experimental configurations (Section 5.1).
 
 Datasets are synthetic stand-ins with matched class counts (see
-repro.data.synthetic; real CIFAR/AG-News are unavailable offline — DESIGN.md
-§8).  Sizes are scaled by ``scale`` so CPU runs finish; the Dirichlet
+repro.data.synthetic: real CIFAR, Tiny-ImageNet and AG-News cannot be
+fetched offline).  Sizes are scaled by ``scale`` so CPU runs finish; the Dirichlet
 non-IID machinery, client counts, participation ratios, local epochs and
 hyper-parameters mirror the paper exactly.
 """

@@ -8,9 +8,11 @@ how the clients actually run is its business:
 
     SequentialExecutor   one jitted lax.scan per client, Python loop over
                          clients — the reference semantics
-    VmapExecutor         pad/stack the sampled clients' batches and vmap the
-                         SAME scan so one jitted XLA call trains every
-                         client in parallel
+    VmapExecutor         stack the sampled clients' full shards and vmap
+                         the SAME scan (or run the client-batched body) so
+                         one jitted XLA call trains every client in
+                         parallel, each step gathering its batch on the
+                         device
     ShardMapExecutor     the multi-device path: the cohort is sharded over a
                          ``("clients",)`` device mesh with shard_map, client
                          shards live device-resident across rounds, and
@@ -24,9 +26,15 @@ how the clients actually run is its business:
                          async path, ready-cohort training delegated to one
                          of the executors above (see the class docstring)
 
-All three consume identical materialized batches (one shared host-RNG draw,
-same order as the historical per-client iterator), so sequential and vmap
+All three consume identical batch picks (one shared host-RNG draw, same
+order as the historical per-client iterator), so sequential and vmap
 outputs agree to float-associativity (~1e-6 on the paper's small models).
+The sequential route gathers each batch on the host; the batched routes
+ship each sampled client's full shard once (flattened to ``(N, D)`` rows
+on the vmap route, lane-dense in the chip's memory) with the ``(S, B)``
+picks, and the round body gathers one step's ``(K, B, ...)`` batch at a
+time: no ``(K, S, B, ...)`` batch stack exists on the host or the
+device.
 
 Masking rules for ragged clients (see ``repro.core.client``):
   * every batch within a client has a uniform size ``min(B, n_k)``; across
@@ -42,9 +50,10 @@ round (FedGKD Eq. 4-5, FedDistill's label table), so their per-example
 teacher tensors are round constants.  Executors therefore invoke
 ``Algorithm.precompute_aux(model, payload, x, y, mask)`` ONCE per round on
 each client's full shard — a single jitted, inference-only batched forward,
-``(K, N_max, ...) -> (K, N_max, C)`` on the stacked path — and gather the
-per-batch rows through the ``MaterializedClient.picks`` indices before the
-training scan.  The gathered pytree reaches ``loss_fn`` as ``aux``; the
+``(K, N_max, ...) -> (K, N_max, C)`` on the stacked path, run as a
+``lax.map`` over chunks of ``PRECOMPUTE_CHUNK`` rows so that its
+activations stay bounded — and gather the per-batch rows through the
+batch picks before the training scan.  The gathered pytree reaches ``loss_fn`` as ``aux``; the
 teacher's parameters never enter the differentiated (vmapped) closure.
 
 Contract for ``precompute_aux`` implementations:
@@ -169,9 +178,11 @@ class RoundContext:
     # ``pad_rows`` rows), so CHURNING wave sizes hit ONE compiled round
     # body instead of retracing per distinct (K, S, B) — see
     # ``AsyncExecutor``.  None (the default) keeps the historical
-    # per-wave-maxima shapes.  Padding is exact, not approximate: phantom
-    # slots/steps are identities through the masking machinery and are
-    # sliced off before anything downstream sees them.
+    # per-wave-maxima shapes; ``run_federated`` sets ``pad_rows`` to the
+    # largest client's shard for synchronous rounds on eager data, since
+    # the round body takes full shards.  Padding is exact, not
+    # approximate: phantom slots/steps are identities through the masking
+    # machinery and are sliced off before anything downstream sees them.
     wave_slots: Optional[int] = None
     pad_steps: Optional[int] = None
     pad_batch: Optional[int] = None
@@ -318,7 +329,7 @@ def materialize_client(rng: np.random.Generator, data: ClientData,
                        batch_size: int, epochs: int,
                        max_batches: Optional[int] = None) -> MaterializedClient:
     """``materialize_picks`` plus the host-side row gather (the sequential
-    and vmap executors feed the gathered batches straight to the device)."""
+    executor feeds the gathered batches straight to the device)."""
     sel = materialize_picks(rng, data, batch_size, epochs, max_batches)
     return MaterializedClient(data.x[sel], data.y[sel], data.n, sel)
 
@@ -331,34 +342,19 @@ def client_from_picks(data: ClientData, sel: np.ndarray) -> MaterializedClient:
     return MaterializedClient(data.x[sel], data.y[sel], data.n, sel)
 
 
-def _pad_and_stack(mats: list[MaterializedClient], k_pad: Optional[int] = None,
-                   s_pad: Optional[int] = None, b_pad: Optional[int] = None):
-    """(K, S, B, ...) arrays + example mask (K, S, B) + pick indices
-    (K, S, B) + step mask (K, S).  Padded picks point at row 0 — harmless,
-    the example mask zero-weights whatever they gather.
-
-    ``k_pad``/``s_pad``/``b_pad`` raise the stack dimensions to fixed
-    targets (never below the cohort maxima): rows beyond ``len(mats)`` are
-    phantom clients with all-zero masks, extra steps/examples are masked
-    pads like any ragged client's — the fixed-slot wave geometry."""
-    S = max(max(m.xs.shape[0] for m in mats), s_pad or 0)
-    B = max(max(m.xs.shape[1] for m in mats), b_pad or 0)
-    k = max(len(mats), k_pad or 0)
-    feat = mats[0].xs.shape[2:]
-    xs = np.zeros((k, S, B) + feat, mats[0].xs.dtype)
-    ys = np.zeros((k, S, B), mats[0].ys.dtype)
-    ex_mask = np.zeros((k, S, B), np.float32)
-    picks = np.zeros((k, S, B), np.int32)
-    step_mask = np.zeros((k, S), bool)
-    for i, m in enumerate(mats):
-        s, b = m.xs.shape[:2]
-        xs[i, :s, :b] = m.xs
-        ys[i, :s, :b] = m.ys
-        ex_mask[i, :s, :b] = 1.0
-        picks[i, :s, :b] = m.picks
-        step_mask[i, :s] = True
-    return (jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(ex_mask),
-            jnp.asarray(picks), jnp.asarray(step_mask))
+def _pad_and_stack(picks: list[np.ndarray], full: tuple,
+                   k_pad: Optional[int] = None, s_pad: Optional[int] = None,
+                   b_pad: Optional[int] = None):
+    """The batched round body's inputs: the full shards ``(fx, fy)`` of
+    ``full`` (from ``_pad_full_data``), example mask (K, S, B), pick
+    indices (K, S, B) and step mask (K, S) on the device.  Padded picks
+    point at row 0 — harmless, the example mask zero-weights whatever they
+    gather.  ``k_pad``/``s_pad``/``b_pad`` as in ``_pad_and_stack_picks``
+    (the fixed-slot wave geometry)."""
+    k = max(len(picks), k_pad or 0)
+    out, ex_mask, step_mask = _pad_and_stack_picks(picks, k, s_pad, b_pad)
+    return (full[0], full[1], jnp.asarray(ex_mask), jnp.asarray(out),
+            jnp.asarray(step_mask))
 
 
 def _pad_and_stack_picks(picks: list[np.ndarray], k_pad: int,
@@ -394,41 +390,92 @@ def _pad_clients_axis(tree: Any, k_pad: int) -> Any:
     return jax.tree_util.tree_map(pad, tree)
 
 
+# Rows of each client's shard that one pass of the teacher precompute
+# takes: the (K, PRECOMPUTE_CHUNK) chunk's activations are what the stage
+# holds at once (ResNet-50 at 64x64: about 1.1 MB an image, so 4 clients x
+# 256 rows ~ 1.1 GB of the chip's 16; PERF.md).  Full shards are padded to
+# a multiple of it.
+PRECOMPUTE_CHUNK = 256
+
+
+def _chunk_rows(rows: int) -> int:
+    """Rows a precompute chunk takes of a ``rows``-row shard."""
+    return max(1, min(PRECOMPUTE_CHUNK, rows))
+
+
+def _full_rows(n: int) -> int:
+    """``n`` rounded up to a whole number of precompute chunks."""
+    c = _chunk_rows(n)
+    return -(-n // c) * c
+
+
 def _pad_full_data(client_data: list[ClientData], cache: Optional[dict] = None,
                    cohort_key=None, k_pad: Optional[int] = None,
                    n_pad: Optional[int] = None):
-    """Stack each client's FULL shard to (K, N_max, ...) + mask for the
-    vmapped ``client_finalize`` / ``precompute_aux`` hooks.
+    """Stack each client's FULL shard to (K, N_max, D) rows, each example
+    flattened to D values (lane-dense on the chip, whose layouts pad a
+    3-channel image's minor axis to 128 lanes), + labels (K, N_max) + mask
+    for the round body, the ``precompute_aux`` and the ``client_finalize``
+    hooks.  N_max is the cohort's largest shard, or ``n_pad`` if larger,
+    rounded up to a whole number of precompute chunks.
 
     Shards are immutable across rounds, so with ``cache``/``cohort_key``
     (the sampled client-id tuple) a repeated cohort skips the host padding
-    work entirely.  The cache holds ONE entry: only a cohort repeated
-    back-to-back (fixed-cohort loops, benchmarks) ever hits — under random
-    partial participation every round keys differently, and retaining
-    misses would pin (K, N_max, ...) device stacks for nothing.
+    and the upload entirely.  The cache holds ONE entry: only a cohort
+    repeated back-to-back (fixed-cohort loops, benchmarks) ever hits —
+    under random partial participation every round keys differently, and
+    retaining misses would pin (K, N_max, ...) device stacks for nothing.
+    ``input_upload_bytes`` counts the image bytes a miss uploads.
 
-    ``k_pad``/``n_pad`` raise the client/row dimensions to fixed targets
-    (fixed-slot waves); phantom rows carry zero values behind a zero mask.
+    ``k_pad`` raises the client dimension to a fixed target (fixed-slot
+    waves); phantom rows carry zero values behind a zero mask.
     """
     if cache is not None and cohort_key is not None:
         hit = cache.get(cohort_key)
         if hit is not None:
             return hit
-    n_max = max(max(d.n for d in client_data), n_pad or 0)
+    n_max = _full_rows(max(max(d.n for d in client_data), n_pad or 0))
     k = max(len(client_data), k_pad or 0)
-    feat = client_data[0].x.shape[1:]
-    xs = np.zeros((k, n_max) + feat, client_data[0].x.dtype)
+    d0 = client_data[0].x
+    xs = np.zeros((k, n_max, int(np.prod(d0.shape[1:]))), d0.dtype)
     ys = np.zeros((k, n_max), client_data[0].y.dtype)
     mask = np.zeros((k, n_max), np.float32)
     for i, d in enumerate(client_data):
-        xs[i, :d.n] = d.x
+        xs[i, :d.n] = d.x.reshape(d.n, -1)
         ys[i, :d.n] = d.y
         mask[i, :d.n] = 1.0
+    tracing.count("input_upload_bytes", xs.nbytes)
     out = jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask)
     if cache is not None and cohort_key is not None:
         cache.clear()                   # single-entry: no device-array pile
         cache[cohort_key] = out
     return out
+
+
+def _chunked_precompute(ctx: "RoundContext", payload, fx, fy, fmask,
+                        x_shape: tuple):
+    """``precompute_aux`` over every client's full shard, ``fx`` (K, R,
+    ...) with examples of shape ``x_shape``: a ``lax.map`` over chunks of
+    ``_chunk_rows(R)`` rows (R padded to a whole number of them inside), so
+    the teacher's activations are a chunk's, not the shard's.  Leaves come
+    back (K, R, ...)."""
+    k, rows = fx.shape[:2]
+    c, r = _chunk_rows(rows), _full_rows(rows)
+    if r != rows:
+        pad = lambda a: jnp.pad(a, ((0, 0), (0, r - rows))
+                                + ((0, 0),) * (a.ndim - 2))
+        fx, fy, fmask = pad(fx), pad(fy), pad(fmask)
+
+    def chunk(i):
+        part = lambda a: jax.lax.dynamic_slice_in_dim(a, i * c, c, axis=1)
+        x = part(fx).reshape((k, c) + tuple(x_shape))
+        return jax.vmap(lambda x, y, m: ctx.algo.precompute_aux(
+            ctx.model, payload, x, y, m))(x, part(fy), part(fmask))
+
+    out = jax.lax.map(chunk, jnp.arange(r // c))    # leaves (r/c, K, c, ..)
+    return jax.tree_util.tree_map(
+        lambda l: jnp.moveaxis(l, 0, 1).reshape((k, r) + l.shape[3:])
+        [:, :rows], out)
 
 
 def tree_stack(trees: list[Any]) -> Any:
@@ -520,32 +567,65 @@ class VmapExecutor:
     def _round_fn(self, ctx: RoundContext) -> Callable:
         fn = ctx.jit_cache.get("round")
         if fn is None:
-            # no buffer donation: the (xs, ys, ex_mask) batch stacks match
-            # no output's shape, so the TPU compiler reports them unusable
+            # no buffer donation: the full shards are reused by the next
+            # round of the same cohort, and the masks match no output
             if ctx.batched_local_update is not None:
                 # client-batched body: one fused cohort program (stacked
                 # params through the model, grouped-conv kernels) instead
                 # of vmapping the per-client scan — same signature
-                fn = jax.jit(ctx.batched_local_update)
+                fn = jax.jit(ctx.batched_local_update,
+                             static_argnames="x_shape")
             else:
-                fn = jax.jit(jax.vmap(ctx.local_update,
-                                      in_axes=(None, None, 0, 0, 0, 0, 0, 0,
-                                               None)))
+                one = ctx.local_update
+
+                def local_update(global_params, payload, states, fx, fy,
+                                 picks, ex_mask, aux, step_mask, lr, *,
+                                 x_shape=None):
+                    return jax.vmap(
+                        functools.partial(one, x_shape=x_shape),
+                        in_axes=(None, None, 0, 0, 0, 0, 0, 0, 0, None))(
+                        global_params, payload, states, fx, fy, picks,
+                        ex_mask, aux, step_mask, lr)
+
+                fn = jax.jit(local_update, static_argnames="x_shape")
             ctx.jit_cache["round"] = fn
         return fn
 
     def _precompute_fn(self, ctx: RoundContext) -> Callable:
+        """``precompute(payload, fx, fy, fmask, x_shape=None) -> aux``:
+        the teacher precompute over every client's full shard, one jitted
+        program (``stage``, also reachable as ``precompute.program``).
+        ``fx`` is (K, N, D) flattened rows of examples shaped ``x_shape``,
+        or (K, N, ...) whole examples when ``x_shape`` is None.  Rows are
+        padded as ``_pad_full_data`` pads them, so shards that it would
+        stack to the same rows share one program; aux leaves come back
+        (K, rows, ...)."""
         fn = ctx.jit_cache.get("precompute")
         if fn is None:
-            def stage(payload, fx, fy, fmask):
+            def stage(payload, fx, fy, fmask, x_shape):
                 # one inference-only batched forward over every client's
-                # full shard: (K, N_max, ...) -> per-example aux leaves
-                return jax.vmap(
-                    lambda x, y, m: ctx.algo.precompute_aux(
-                        ctx.model, payload, x, y, m))(fx, fy, fmask)
+                # full shard, in chunks of rows: (K, N_max, D) ->
+                # per-example aux leaves
+                return _chunked_precompute(ctx, payload, fx, fy, fmask,
+                                           x_shape)
 
-            fn = jax.jit(stage)
-            ctx.jit_cache["precompute"] = fn
+            staged = jax.jit(stage, static_argnames="x_shape")
+
+            def precompute(payload, fx, fy, fmask, x_shape=None):
+                if x_shape is None:
+                    x_shape = fx.shape[2:]
+                    fx = fx.reshape(fx.shape[:2] + (-1,))
+                n = fx.shape[1]
+                rows = _full_rows(max(n, ctx.pad_rows or 0))
+                if rows != n:
+                    grow = lambda a: jnp.pad(a, ((0, 0), (0, rows - n))
+                                             + ((0, 0),) * (a.ndim - 2))
+                    fx, fy, fmask = grow(fx), grow(fy), grow(fmask)
+                return staged(payload, fx, fy, fmask,
+                              x_shape=tuple(x_shape))
+
+            precompute.program = staged
+            fn = ctx.jit_cache["precompute"] = precompute
         return fn
 
     def _gather_fn(self, ctx: RoundContext) -> Callable:
@@ -580,11 +660,6 @@ class VmapExecutor:
             ctx.jit_cache["state"] = fn
         return fn
 
-    # -- the stacked computation (ShardMapExecutor overrides this) -------
-    def _execute(self, ctx, global_params, payload, states_stacked,
-                 xs, ys, ex_mask, aux, step_mask):
-        return self._round_fn(ctx)(global_params, payload, states_stacked,
-                                   xs, ys, ex_mask, aux, step_mask, ctx.lr)
 
     def _incremental_aux(self, ctx: RoundContext, payload, parts_spec,
                          client_ids, client_data, full):
@@ -674,16 +749,17 @@ class VmapExecutor:
         # clients (and rows/steps/batch to the population-wide targets) so
         # every wave, whatever its size, runs the SAME compiled body
         k_pad = max(k, ctx.wave_slots) if ctx.wave_slots else k
-        full = None
+        x_shape = tuple(client_data[0].x.shape[1:])
         aux_full = None
-        if ctx.has_precompute or ctx.has_finalize:
-            with tracing.span("fl.exec.full_shards"):
-                full = _pad_full_data(
-                    client_data,
-                    cache=ctx.jit_cache.setdefault("full_data", {}),
-                    cohort_key=(tuple(client_ids)
-                                if client_ids is not None else None),
-                    k_pad=k_pad, n_pad=ctx.pad_rows)
+        with tracing.span("fl.exec.full_shards"):
+            full = _pad_full_data(
+                client_data, cache=ctx.jit_cache.setdefault("full_data", {}),
+                cohort_key=(tuple(client_ids)
+                            if client_ids is not None else None),
+                k_pad=k_pad, n_pad=ctx.pad_rows)
+        # the hooks that take whole examples see the shards reshaped
+        whole = lambda: ((full[0].reshape(full[0].shape[:2] + x_shape),)
+                         + full[1:])
         if ctx.has_precompute:
             # rows the teacher forward runs over, against the real ones
             tracing.count("precompute_rows", full[0].shape[0]
@@ -696,33 +772,35 @@ class VmapExecutor:
                 if parts_spec is not None:
                     aux_full = self._incremental_aux(
                         ctx, payload, parts_spec, client_ids, client_data,
-                        full)
+                        whole())
                 else:
                     # dispatch the (async) teacher forward FIRST: it needs
                     # no batch picks, so the device crunches it while the
-                    # host materializes and pads the round's batches below
-                    aux_full = self._precompute_fn(ctx)(payload, *full)
+                    # host draws and pads the round's batch picks below
+                    tracing.count("precompute_chunks", full[0].shape[1]
+                                  // _chunk_rows(full[0].shape[1]))
+                    aux_full = self._precompute_fn(ctx)(payload, *full,
+                                                        x_shape=x_shape)
 
         with tracing.span("fl.exec.picks"):
-            mats = ([client_from_picks(d, p)
-                     for d, p in zip(client_data, picks)]
-                    if picks is not None else
-                    [materialize_client(rng, d, ctx.batch_size, ctx.epochs,
+            picks = (list(picks) if picks is not None else
+                     [materialize_picks(rng, d, ctx.batch_size, ctx.epochs,
                                         ctx.max_batches)
-                     for d in client_data])
+                      for d in client_data])
         with tracing.span("fl.exec.stack"):
-            xs, ys, ex_mask, picks, step_mask = _pad_and_stack(
-                mats, k_pad=k_pad, s_pad=ctx.pad_steps, b_pad=ctx.pad_batch)
+            fx, fy, ex_mask, picks, step_mask = _pad_and_stack(
+                picks, full, k_pad=k_pad, s_pad=ctx.pad_steps,
+                b_pad=ctx.pad_batch)
             states_real = tree_stack(client_states)
             states_stacked = _pad_clients_axis(states_real, k_pad)
         ctx.note_round_shape(("round", ctx.telemetry["round_body"])
-                             + tuple(xs.shape))
+                             + tuple(fx.shape) + tuple(picks.shape))
         with tracing.span("fl.exec.body"):
             aux = (self._gather_fn(ctx)(aux_full, picks)
                    if ctx.has_precompute else ())
-            params_padded, mloss_padded = self._execute(
-                ctx, global_params, payload, states_stacked, xs, ys,
-                ex_mask, aux, step_mask)
+            params_padded, mloss_padded = self._round_fn(ctx)(
+                global_params, payload, states_stacked, fx, fy, picks,
+                ex_mask, aux, step_mask, ctx.lr, x_shape=x_shape)
         # drop phantom slots before anything downstream sees them
         params_stacked = (jax.tree_util.tree_map(lambda l: l[:k],
                                                  params_padded)
@@ -730,7 +808,7 @@ class VmapExecutor:
         mloss = mloss_padded[:k] if k_pad > k else mloss_padded
 
         if ctx.has_finalize:
-            fx, fy, fmask = full
+            fx, fy, fmask = whole()
             extras_stacked = self._finalize_fn(ctx)(params_stacked, fx[:k],
                                                     fy[:k], fmask[:k],
                                                     payload)
@@ -753,8 +831,8 @@ class VmapExecutor:
             with tracing.span("fl.exec.losses"):
                 losses = np.asarray(mloss).astype(float).tolist()
             tracing.count("host_syncs")
-        return RoundResult(uploads, [float(m.n) for m in mats], losses,
-                           new_states)
+        return RoundResult(uploads, [float(d.n) for d in client_data],
+                           losses, new_states)
 
 
 class ShardMapExecutor(VmapExecutor):
@@ -793,33 +871,25 @@ class ShardMapExecutor(VmapExecutor):
 
             def per_shard(gp, pl, st, fx, fy, picks, ex_mask, step_mask,
                           aux_full):
-                # batch rows gathered from the resident slab ON the device
-                # that owns the client — the host never ships (S, B, ...)
+                # each step's batch rows are gathered from the resident
+                # slab ON the device that owns the client, inside the same
+                # round bodies the vmap route runs — the host never ships
                 # batch tensors for this path
+                aux_rows = jax.tree_util.tree_map(
+                    lambda l: jax.vmap(lambda a, p: a[p])(l, picks),
+                    aux_full)
                 if ctx.batched_local_update is not None:
                     # client-batched body on this shard's g resident
-                    # clients: gather every client's batches, then run the
-                    # fused stacked round (grouped-conv route) — no vmap
-                    gather = jax.vmap(lambda f, p: f[p])
-                    xs = gather(fx, picks)
-                    ys = gather(fy, picks)
-                    aux_rows = jax.tree_util.tree_map(
-                        lambda l: jax.vmap(lambda a, p: a[p])(l, picks),
-                        aux_full)
+                    # clients: the fused stacked round (grouped-conv
+                    # route) — no vmap
                     return ctx.batched_local_update(
-                        gp, pl, st, xs, ys, ex_mask, aux_rows, step_mask,
-                        ctx.lr)
-
-                def one(st_i, fx_i, fy_i, p_i, em_i, sm_i, aux_i):
-                    xs = fx_i[p_i]
-                    ys = fy_i[p_i]
-                    aux_rows = jax.tree_util.tree_map(lambda l: l[p_i],
-                                                      aux_i)
-                    return ctx.local_update(gp, pl, st_i, xs, ys, em_i,
-                                            aux_rows, sm_i, ctx.lr)
-
-                return jax.vmap(one)(st, fx, fy, picks, ex_mask, step_mask,
-                                     aux_full)
+                        gp, pl, st, fx, fy, picks, ex_mask, aux_rows,
+                        step_mask, ctx.lr)
+                return jax.vmap(
+                    ctx.local_update,
+                    in_axes=(None, None, 0, 0, 0, 0, 0, 0, 0, None))(
+                    gp, pl, st, fx, fy, picks, ex_mask, aux_rows,
+                    step_mask, ctx.lr)
 
             fn = shard_map_compat(
                 per_shard, mesh,
@@ -838,9 +908,8 @@ class ShardMapExecutor(VmapExecutor):
             from repro.sharding import shard_map_compat
 
             def per_shard(pl, fx, fy, fmask):
-                return jax.vmap(
-                    lambda x, y, m: ctx.algo.precompute_aux(
-                        ctx.model, pl, x, y, m))(fx, fy, fmask)
+                return _chunked_precompute(ctx, pl, fx, fy, fmask,
+                                           fx.shape[2:])
 
             fn = shard_map_compat(
                 per_shard, mesh,
@@ -917,7 +986,10 @@ class ShardMapExecutor(VmapExecutor):
         entries: list[Optional[dict]] = []
         for i, d in enumerate(client_data):
             cid = client_ids[i] if client_ids is not None else None
+            uploads = ctx.placement.host_transfers
             entries.append(ctx.placement.get(cid, d, devices[i // g]))
+            if ctx.placement.host_transfers > uploads:
+                tracing.count("input_upload_bytes", entries[-1]["x"].nbytes)
         feat = client_data[0].x.shape[1:]
         x_dtype = client_data[0].x.dtype
         pad_width = ((0, 0),) * len(feat)
@@ -1094,6 +1166,8 @@ class ShardMapExecutor(VmapExecutor):
                     ctx, mesh, payload, parts_spec, client_ids, client_data,
                     full)
             else:
+                tracing.count("precompute_chunks",
+                              _full_rows(rows) // _chunk_rows(rows))
                 aux_full = self._sharded_precompute_fn(ctx, mesh)(payload,
                                                                   *full)
 

@@ -310,6 +310,12 @@ def run_federated(task: PaperTask, algo: Algorithm,
                           checkpoint_dir=checkpoint_dir,
                           checkpoint_every=checkpoint_every, resume=resume)
 
+    if pop is None and ctx.pad_rows is None:
+        # the batched routes stack every cohort's full shards to the
+        # largest client's rows: one round-body and one precompute shape
+        # for the run, however the cohorts' largest shards vary
+        ctx.pad_rows = max(c.n for c in data.clients)
+
     records: list[RoundRecord] = []
     local_acc = 0.0
     uploads: list[dict] = []

@@ -246,12 +246,13 @@ def test_masked_step_is_identity(tiny_setup):
     params = model.init(jax.random.PRNGKey(1))
     local = client_lib.make_local_update(
         algorithms.make("fedavg").loss_fn(model), sgd(momentum=0.9))
-    xs = jnp.zeros((2, 3, task.feat_dim))
-    ys = jnp.zeros((2, 3), jnp.int32)
+    fx = jnp.zeros((5, task.feat_dim))
+    fy = jnp.zeros((5,), jnp.int32)
+    picks = jnp.zeros((2, 3), jnp.int32)
     ex_mask = jnp.zeros((2, 3), jnp.float32)
     step_mask = jnp.zeros((2,), bool)
-    new_params, mloss = jax.jit(local)(params, (), (), xs, ys, ex_mask, (),
-                                       step_mask, 0.1)
+    new_params, mloss = jax.jit(local)(params, (), (), fx, fy, picks,
+                                       ex_mask, (), step_mask, 0.1)
     assert _max_param_diff(params, new_params) == 0.0
     assert float(mloss) == 0.0
 
@@ -286,11 +287,19 @@ def test_materialize_max_batches_rng_consumption():
 
 
 def test_pad_and_stack_masks():
-    mk = lambda s, b: ex.MaterializedClient(
-        np.ones((s, b, 2), np.float32), np.ones((s, b), np.int64), s * b,
-        np.arange(s * b, dtype=np.int32).reshape(s, b) % max(1, s * b // 2))
-    xs, ys, ex_mask, picks, step_mask = ex._pad_and_stack([mk(3, 4), mk(1, 2)])
-    assert xs.shape == (2, 3, 4, 2)
+    mk = lambda s, b: (np.arange(s * b, dtype=np.int32).reshape(s, b)
+                       % max(1, s * b // 2))
+    clients = [ClientData(np.ones((6, 2, 2), np.float32),
+                          np.ones(6, np.int64)),
+               ClientData(np.ones((2, 2, 2), np.float32),
+                          np.ones(2, np.int64))]
+    full = ex._pad_full_data(clients)
+    xs, ys, ex_mask, picks, step_mask = ex._pad_and_stack(
+        [mk(3, 4), mk(1, 2)], full)
+    # the body's inputs are the full shards, flattened per example: no
+    # (K, S, B, ...) batch stack
+    assert xs.shape == (2, 6, 4) and ys.shape == (2, 6)
+    assert xs is full[0] and ys is full[1]
     assert picks.shape == (2, 3, 4)
     assert float(ex_mask[0].sum()) == 12.0
     assert float(ex_mask[1].sum()) == 2.0

@@ -127,11 +127,12 @@ def test_self_seconds_is_duration_less_children(fedgkd_run):
 
 
 def test_precompute_rows_are_the_padded_and_the_real_shards(fedgkd_run):
+    """Every cohort's shards are padded to the largest client's rows."""
     rec, h = fedgkd_run
     for rnd in h.records:
         n = [SIZES[c] for c in rnd.sampled]
         assert rec.counter("precompute_rows", [rnd.round]) == [
-            len(n) * max(n)]
+            len(n) * max(SIZES)]
         assert rec.counter("precompute_rows_real", [rnd.round]) == [sum(n)]
 
 
@@ -260,14 +261,18 @@ def _lowered(task, algo_name, feat, k=2, n=16, s=2, b=4):
     stage, gather = vx._precompute_fn(ctx), vx._gather_fn(ctx)
     aux_full = jax.eval_shape(stage, payload, *full)
     aux = jax.eval_shape(gather, aux_full, picks)
+    # the round body takes the shards as the executor stacks them: one
+    # flattened row per example
+    full = (sds((k, n, math.prod(feat)), jnp.float32),) + full[1:]
     states = executor.tree_stack([algo.init_client_state(i, params)
                                   for i in range(k)])
     body = vx._round_fn(ctx).lower(
-        params, payload, states, sds((k, s, b) + feat, jnp.float32),
-        sds((k, s, b), jnp.int32), sds((k, s, b), jnp.float32), aux,
-        sds((k, s), jnp.bool_), ctx.lr)
+        params, payload, states, full[0], full[1], picks,
+        sds((k, s, b), jnp.float32), aux, sds((k, s), jnp.bool_), ctx.lr,
+        x_shape=feat)
     return ctx, {"body": body.as_text(),
-                 "stage": stage.lower(payload, *full).as_text(),
+                 "stage": stage.program.lower(payload, *full,
+                                              x_shape=feat).as_text(),
                  "gather": gather.lower(aux_full, picks).as_text()}
 
 
@@ -284,3 +289,54 @@ def test_jitted_program_names_are_stable(task, feat, round_body):
     assert "module @jit_local_update" in text["body"]
     assert "module @jit_stage" in text["stage"]
     assert "module @jit_precompute_gather" in text["gather"]
+
+
+# -- the round's inputs: chunks of the teacher stage, bytes uploaded ------
+
+def test_precompute_chunks_are_the_padded_rows_over_the_chunk(monkeypatch):
+    """Every shard is padded to the largest client's 150 rows, rounded up
+    to chunks of 64: 3 passes of the teacher a round."""
+    monkeypatch.setattr(executor, "PRECOMPUTE_CHUNK", 64)
+    task = _task(0.5)
+    rec = tracing.enable()
+    try:
+        h = fl_loop.run_federated(task, algorithms.make("fedgkd"),
+                                  _data(task), seed=0, executor="vmap")
+    finally:
+        tracing.disable()
+    rounds = [r.round for r in h.records]
+    assert rec.counter("precompute_chunks", rounds) == [3, 3]
+    assert rec.counter("precompute_rows", rounds) == [3 * 192, 3 * 192]
+
+
+def test_input_upload_bytes_are_the_shards_sent_and_zero_on_a_repeat():
+    """A cohort's first round uploads its shards once, (3, 150 rows, D)
+    float32 padded to the largest shard; the same cohort again reuses
+    them and reads 0; another cohort uploads anew.  FedAvg has no teacher
+    stage and counts no chunks."""
+    task = _task(0.5)
+    algo = algorithms.make("fedavg")
+    model = fl_loop.make_model(task, algo.needs_projection_head)
+    ctx = executor.RoundContext(algo=algo, model=model, opt=sgd(), lr=0.1,
+                                batch_size=32, epochs=1)
+    params = model.init(jax.random.PRNGKey(0))
+    payload = algo.round_payload(
+        algo.init_server(params, model, task.num_classes),
+        jax.random.PRNGKey(1))
+    data = _data(task)
+    vx = executor.VmapExecutor()
+    rec = tracing.enable()
+    try:
+        for r, ids in enumerate([(5, 0, 2), (5, 0, 2), (1, 3, 4)], 1):
+            with tracing.round_span(r):
+                vx.run_round(ctx, params, payload,
+                             [algo.init_client_state(i, params)
+                              for i in ids],
+                             [data.clients[i] for i in ids],
+                             np.random.default_rng(r), client_ids=ids)
+    finally:
+        tracing.disable()
+    one = 150 * TOY.feat_dim * 4
+    assert rec.counter("input_upload_bytes", [1, 2, 3]) == [
+        3 * one, 0, 3 * one * 130 / 150]
+    assert rec.counter("precompute_chunks", [1, 2, 3]) == [0, 0, 0]
